@@ -48,6 +48,7 @@ from typing import Dict, List, Optional, Sequence
 import jax
 import numpy as np
 
+from repro import compile_cache
 from repro.configs.vitdet_l import SIM
 from repro.core import vit_backbone as vb
 from repro.data import synthetic_video as sv
@@ -341,6 +342,7 @@ def run(ctx: dict) -> list:
 
 
 def main(argv=None) -> int:
+    compile_cache.enable()
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--smoke", action="store_true",
                     help="fewer frames/clients (CI sanity lane)")

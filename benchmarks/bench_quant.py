@@ -21,6 +21,7 @@ from pathlib import Path
 import numpy as np
 
 import common
+from repro import compile_cache
 from repro.configs.vitdet_l import SIM
 from repro.core.partition import RegionPlan
 from repro.offload.simulator import ServerModel
@@ -149,6 +150,7 @@ def run(ctx: dict) -> list:
 
 
 def main(argv=None) -> int:
+    compile_cache.enable()
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--smoke", action="store_true",
                     help="2-candidate int8 ladder, 3 frames (CI lane)")

@@ -56,6 +56,7 @@ from typing import Dict, List, Optional
 import jax
 import numpy as np
 
+from repro import compile_cache
 from repro.configs.vitdet_l import SIM
 from repro.core import partition as pt
 from repro.core import vit_backbone as vb
@@ -718,6 +719,7 @@ def run(ctx: dict) -> list:
 
 
 def main(argv=None) -> int:
+    compile_cache.enable()
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--smoke", action="store_true",
                     help="fewer frames (CI sanity lane)")

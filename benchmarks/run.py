@@ -6,6 +6,8 @@ import time
 
 
 def main(argv=None) -> int:
+    from repro import compile_cache
+    compile_cache.enable()
     from benchmarks import (bench_backbone, bench_multiclient, bench_quant,
                             bench_reuse, bench_robustness, bench_serving,
                             fig5_restoration, fig8_overall, fig9_delays,

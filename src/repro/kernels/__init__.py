@@ -17,9 +17,12 @@ Each kernel is a package ``<name>/{kernel.py, ops.py, ref.py}``:
                      (repro.quant) for QKV/MLP/head projections.
 
 TPU is the TARGET (pl.pallas_call + BlockSpec VMEM tiling, MXU-aligned
-block shapes); on this CPU container every kernel is validated with
-``interpret=True`` against its ``ref.py`` pure-jnp oracle
-(tests/test_kernels.py sweeps shapes and dtypes).
+block shapes).  Every ``kernel.py`` entry takes ``interpret`` as a
+required argument; the ``ops.py`` wrappers pass ``interpret=True`` off
+the TPU, where tests/test_kernels.py checks each kernel against its
+``ref.py`` pure-jnp oracle across shapes and dtypes, and
+tests/test_tpu_compile.py compiles the serving kernels at ViTDet-L
+widths for a described v5e.  ``chip_smoke.py`` runs them on the chip.
 
 The jnp model code paths remain the default for dry-run lowering (XLA
 cost analysis reads the jnp HLO); ``ops.py`` wrappers are the swap-in

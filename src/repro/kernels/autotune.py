@@ -32,6 +32,7 @@ import os
 import re
 import threading
 import time
+import warnings
 from pathlib import Path
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
@@ -47,7 +48,8 @@ CACHE_ENV = "REPRO_AUTOTUNE_CACHE"
 FLASH_CANDIDATES = ({"bq": 128, "bk": 128}, {"bq": 128, "bk": 256},
                     {"bq": 256, "bk": 256}, {"bq": 256, "bk": 512},
                     {"bq": 512, "bk": 512})
-WINDOW_CANDIDATES = ({"wb": 4}, {"wb": 8}, {"wb": 16}, {"wb": 32})
+# window blocks stay whole sublane tiles (window_attention.ops.resolve_wb)
+WINDOW_CANDIDATES = ({"wb": 8}, {"wb": 16}, {"wb": 32})
 DECODE_CANDIDATES = ({"bs": 256}, {"bs": 512}, {"bs": 1024})
 MATMUL_CANDIDATES = ({"bm": 128, "bn": 128, "bk": 128},
                      {"bm": 256, "bn": 128, "bk": 128},
@@ -196,12 +198,17 @@ def _time_us(fn: Callable[[], jnp.ndarray], reps: int = 3) -> float:
 
 def tune(kernel: str, bucket: str, candidates: Sequence[Dict],
          bench: Callable[[Dict], Optional[Callable[[], jnp.ndarray]]], *,
-         force: bool = False, reps: int = 3) -> Optional[Dict]:
+         default: Optional[Dict] = None, force: bool = False,
+         reps: int = 3) -> Optional[Dict]:
     """Sweep ``candidates`` for (kernel, bucket); cache and return the
     winner.  ``bench(params)`` returns a nullary callable running the
     kernel with those params, or None when the candidate is invalid for
-    the shape.  Returns the cached/tuned params, or None when tuning is
-    disabled or skipped (off-TPU without force)."""
+    the shape.  A candidate that fails to lower or run is reported as a
+    ``RuntimeWarning`` with its error and left out; when it is
+    ``default`` — the block the kernel runs untuned — the sweep raises,
+    because the served executables would fail the same way.  Returns the
+    cached/tuned params, or None when tuning is disabled or skipped
+    (off-TPU without force)."""
     if not enabled():
         return None
     cached = lookup(kernel, bucket)
@@ -216,8 +223,15 @@ def tune(kernel: str, bucket: str, candidates: Sequence[Dict],
             continue
         try:
             results.append((_time_us(fn, reps=reps), dict(params)))
-        except Exception:               # candidate failed to lower/run
-            continue
+        except Exception as e:          # candidate failed to lower/run
+            if default is not None and dict(params) == dict(default):
+                raise RuntimeError(
+                    f"autotune {kernel} [{bucket}]: the kernel default "
+                    f"{params} failed") from e
+            warnings.warn(
+                f"autotune {kernel} [{bucket}]: candidate {params} "
+                f"failed: {type(e).__name__}: {str(e)[:500]}",
+                RuntimeWarning, stacklevel=2)
     if not results:
         return None
     us, params = min(results, key=lambda r: r[0])
@@ -258,27 +272,36 @@ def matmul_bucket(M: int, N: int, K: int, act_dtype, weight_dtype) -> str:
 def tune_window(B: int, T: int, H: int, Dh: int, window: int, *,
                 KV: Optional[int] = None, dtype=jnp.float32,
                 force: bool = False) -> Optional[Dict]:
+    """Sweep the window block on the flagged (``win_valid``) kernel: the
+    bucket serves the padded executables too, and the flagged kernel's
+    block rules are the stricter of the two."""
+    from repro.kernels.window_attention import kernel as _wk
     from repro.kernels.window_attention import ops as _win
     KV = H if KV is None else KV
+    BW = B * (T // window)
     rng = jax.random.PRNGKey(0)
     q = jax.random.normal(rng, (B, T, H, Dh), dtype)
     k = jax.random.normal(rng, (B, T, KV, Dh), dtype)
     v = jax.random.normal(rng, (B, T, KV, Dh), dtype)
+    win_valid = jnp.full((B,), T // window, jnp.int32)
 
     def bench(params):
         wb = params["wb"]
-        if wb > B * (T // window):
+        if _win.resolve_wb(wb, BW) != wb:
             return None
-        return lambda: _win.window_attention(q, k, v, window, wb=wb)
+        return lambda: _win.window_attention(q, k, v, window, wb=wb,
+                                             win_valid=win_valid)
 
     return tune("window_attention",
                 window_bucket(B, T, H, Dh, window, dtype),
-                WINDOW_CANDIDATES, bench, force=force)
+                WINDOW_CANDIDATES, bench,
+                default={"wb": _wk.DEFAULT_WB}, force=force)
 
 
 def tune_flash(B: int, T: int, S: int, H: int, Dh: int, *,
                KV: Optional[int] = None, causal: bool = False,
                dtype=jnp.float32, force: bool = False) -> Optional[Dict]:
+    from repro.kernels.flash_attention import kernel as _fk
     from repro.kernels.flash_attention import ops as _flash
     KV = H if KV is None else KV
     rng = jax.random.PRNGKey(0)
@@ -292,12 +315,15 @@ def tune_flash(B: int, T: int, S: int, H: int, Dh: int, *,
 
     return tune("flash_attention",
                 flash_bucket(B, T, S, H, KV, Dh, causal, dtype),
-                FLASH_CANDIDATES, bench, force=force)
+                FLASH_CANDIDATES, bench,
+                default={"bq": _fk.DEFAULT_BQ, "bk": _fk.DEFAULT_BK},
+                force=force)
 
 
 def tune_decode(B: int, S: int, H: int, Dh: int, *,
                 KV: Optional[int] = None, dtype=jnp.float32,
                 force: bool = False) -> Optional[Dict]:
+    from repro.kernels.decode_attention import kernel as _dk
     from repro.kernels.decode_attention import ops as _dec
     KV = H if KV is None else KV
     rng = jax.random.PRNGKey(0)
@@ -311,12 +337,14 @@ def tune_decode(B: int, S: int, H: int, Dh: int, *,
                                              bs=params["bs"])
 
     return tune("decode_attention", decode_bucket(B, S, H, KV, Dh, dtype),
-                DECODE_CANDIDATES, bench, force=force)
+                DECODE_CANDIDATES, bench,
+                default={"bs": _dk.DEFAULT_BS}, force=force)
 
 
 def tune_matmul(M: int, N: int, K: int, *, out_dtype=jnp.float32,
                 force: bool = False) -> Optional[Dict]:
     """Sweep the int8 GEMM block sizes for an (M, N, K) shape bucket."""
+    from repro.kernels.int8_matmul import kernel as _mk
     from repro.kernels.int8_matmul import ops as _mm
     rng = np.random.default_rng(0)
     xq = jnp.asarray(rng.integers(-127, 128, (M, K), dtype=np.int8))
@@ -332,4 +360,6 @@ def tune_matmul(M: int, N: int, K: int, *, out_dtype=jnp.float32,
 
     return tune("int8_matmul",
                 matmul_bucket(M, N, K, jnp.int8, jnp.int8),
-                MATMUL_CANDIDATES, bench, force=force)
+                MATMUL_CANDIDATES, bench,
+                default={"bm": _mk.DEFAULT_BM, "bn": _mk.DEFAULT_BN,
+                         "bk": _mk.DEFAULT_BK}, force=force)

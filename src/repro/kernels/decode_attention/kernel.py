@@ -81,7 +81,7 @@ def _decode_kernel(kv_len_ref, q_ref, k_ref, v_ref, o_ref,
 def decode_attention_kernel(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                             kv_len: jnp.ndarray, *, scale: float,
                             bs: int = DEFAULT_BS,
-                            interpret: bool = True) -> jnp.ndarray:
+                            interpret: bool) -> jnp.ndarray:
     """q: (B, KV, Gp, Dh); k/v: (B, KV, S, Dh); kv_len: (B,) int32.
     S % bs == 0 (ops.py pads).  Returns (B, KV, Gp, Dh) in q.dtype."""
     B, KV, Gp, Dh = q.shape

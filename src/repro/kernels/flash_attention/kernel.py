@@ -95,7 +95,7 @@ def flash_attention_kernel(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
                            causal: bool, scale: float,
                            bq: int = DEFAULT_BQ, bk: int = DEFAULT_BK,
                            kv_valid: int = 0,
-                           interpret: bool = True) -> jnp.ndarray:
+                           interpret: bool) -> jnp.ndarray:
     """q: (B, H, T, Dh); k/v: (B, KV, S, Dh); H = KV * G.  T % bq == 0,
     S % bk == 0 (ops.py pads).  ``kv_valid``: number of real (unpadded)
     keys; 0 means all S.  Returns (B, H, T, Dh) in q.dtype."""

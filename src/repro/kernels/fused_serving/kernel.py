@@ -23,9 +23,10 @@ sequence in HBM.  These two kernels collapse each seam into one pass:
                         through one of d^2 upsample permutations, or a
                         spliced reuse tile.  ``perm`` is a small
                         (d^2+1, w^2, w^2) one-hot table; the gather is an
-                        MXU matmul (one-hot f32 rows select exactly one
-                        finite activation row, so the product is
-                        bit-identical to the scatter formulation).
+                        MXU matmul at HIGHEST precision (one-hot f32 rows
+                        select exactly one finite activation row, so the
+                        product is bit-identical to the scatter
+                        formulation).
 
 Both kernels are pure data movement + one add/matmul, so their Pallas
 and jnp reference paths agree bitwise (tests/test_fused_serving.py).
@@ -47,7 +48,7 @@ def _pack_pos_body(src_ref, nw_ref, bank_ref, pos_ref, o_ref):
 
 def pack_pos_kernel(bank: jnp.ndarray, pos_bank: jnp.ndarray,
                     win_src: jnp.ndarray, nw: jnp.ndarray, *,
-                    interpret: bool = True) -> jnp.ndarray:
+                    interpret: bool) -> jnp.ndarray:
     """bank: (B, nbank, w2, C); pos_bank: (nbank, w2, C);
     win_src: (B, nw_pad) i32; nw: (B,) i32.  Returns (B, nw_pad, w2, C)
     packed windows with positions added and pad windows zeroed."""
@@ -75,14 +76,17 @@ def pack_pos_kernel(bank: jnp.ndarray, pos_bank: jnp.ndarray,
 
 def _restore_body(src_idx_ref, map_idx_ref, src_ref, perm_ref, o_ref):
     blk = src_ref[...].astype(jnp.float32)            # (w2, D)
+    # HIGHEST: at Mosaic's default precision an f32 matmul rounds its
+    # operands to bf16, which would round every restored activation
     o_ref[...] = jax.lax.dot_general(
         perm_ref[...], blk, (((1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32).astype(o_ref.dtype)
 
 
 def restore_gather_kernel(src: jnp.ndarray, perm: jnp.ndarray,
                           out_src: jnp.ndarray, out_map: jnp.ndarray, *,
-                          interpret: bool = True) -> jnp.ndarray:
+                          interpret: bool) -> jnp.ndarray:
     """src: (B, nsrc, w2, D) source bank [packed windows | reuse tiles];
     perm: (d^2+1, w2, w2) f32 one-hot token permutations; out_src /
     out_map: (B, nout) i32.  Returns (B, nout, w2, D) restored windows

@@ -54,7 +54,7 @@ def int8_matmul_kernel(xq: jnp.ndarray, wq: jnp.ndarray,
                        out_dtype=jnp.float32,
                        bm: int = DEFAULT_BM, bn: int = DEFAULT_BN,
                        bk: int = DEFAULT_BK,
-                       interpret: bool = True) -> jnp.ndarray:
+                       interpret: bool) -> jnp.ndarray:
     """xq: (M, K) int8; wq: (K, N) int8; sx: (M, 1) f32 per-row
     activation scales; sw: (1, N) f32 per-output-channel weight scales.
     M % bm == K % bk == N % bn == 0 (ops.py pads).  Returns (M, N) in

@@ -9,11 +9,17 @@ Two small data-movement kernels backing the paper's §III hot spots:
                 upsample of §III-B (restore_full).
 
 Both are bandwidth-bound reshapes+reductions; the kernels exist to keep
-the op on-chip when fused into the packing pipeline (HBM -> VMEM once),
-with channel tiles of 128 lanes and row blocks sized to the VMEM budget.
+the op on-chip when fused into the packing pipeline (HBM -> VMEM once).
 
-    avg_pool grid = (B, Hout / RB, C / BC)
-      in  block (RB*d, W,   BC)      out block (RB, W/d, BC)
+avg_pool reads the grid *planar* — (B*C, H/d, d*W), W on lanes, the d
+input rows of each output row side by side — which is how XLA already
+lays out a few-channel image on the TPU, so an RGB frame neither pads 3
+channels to 128 lanes nor needs a relayout.  The d row taps are lane
+slices and the d column taps, with their compaction to W/d lanes, one
+matmul against a constant 1/d^2 pooling matrix (HIGHEST precision):
+
+    avg_pool grid = (N / NB, Ho / RB)
+      in  block (NB, RB, d*W)   pool (CW, CO)   out block (NB, RB, W/d)
     upsample grid = (B, Hin / RB, C / BC)
       in  block (RB,   W,   BC)      out block (RB*d, W*d, BC)
 """
@@ -29,11 +35,21 @@ DEFAULT_BC = 128
 DEFAULT_RB = 8
 
 
-def _avg_pool_kernel(x_ref, o_ref, *, d: int):
-    x = x_ref[...].astype(jnp.float32)                # (RB*d, W, BC)
-    RBd, W, BC = x.shape
-    x = x.reshape(RBd // d, d, W // d, d, BC)
-    o_ref[...] = jnp.mean(x, axis=(1, 3)).astype(o_ref.dtype)
+def _avg_pool_kernel(x_ref, m_ref, o_ref, *, taps: int, stride: int):
+    nb, rb, wo = o_ref.shape
+    x = x_ref[...].astype(jnp.float32)                # (NB, RB, d*W)
+    x = x.reshape(nb * rb, x.shape[-1])
+    m = m_ref[...]                                    # (CW, CO)
+    cw, co = m.shape
+    for j in range(wo // co):
+        rows = x[:, j * cw:(j + 1) * cw]
+        for a in range(1, taps):
+            lo = a * stride + j * cw
+            rows = rows + x[:, lo:lo + cw]
+        o = jnp.dot(rows, m, precision=jax.lax.Precision.HIGHEST,
+                    preferred_element_type=jnp.float32)
+        o_ref[:, :, j * co:(j + 1) * co] = o.reshape(nb, rb, co).astype(
+            o_ref.dtype)
 
 
 def _nn_upsample_kernel(x_ref, o_ref, *, d: int):
@@ -43,25 +59,31 @@ def _nn_upsample_kernel(x_ref, o_ref, *, d: int):
     o_ref[...] = x.reshape(RB * d, W * d, BC)
 
 
-def avg_pool_kernel(x: jnp.ndarray, d: int, *, rb: int = DEFAULT_RB,
-                    bc: int = DEFAULT_BC, interpret: bool = True):
-    """x: (B, H, W, C) with H % (rb*d) == 0, W % d == 0, C % bc == 0."""
-    B, H, W, C = x.shape
-    Ho, Wo = H // d, W // d
+def avg_pool_kernel(x: jnp.ndarray, pool: jnp.ndarray, *, taps: int,
+                    nb: int, rb: int, interpret: bool):
+    """x: (N, Ho, d*W) row-paired planes -> (N, Ho, W/d) d x d means.
+
+    ``pool`` is (CW, CO): with ``taps == d`` it pools the columns of one
+    CW-lane chunk of a row, and the kernel first sums the d rows' chunks
+    (lane slices W apart); with ``taps == 1`` it pools the whole d*W row
+    pair at once.  ``nb`` divides N; ``rb`` divides Ho and is a multiple
+    of the sublane tile or the whole axis (ops.py picks all three)."""
+    N, Ho, L = x.shape
+    cw, co = pool.shape
+    Wo = L // taps // (cw // co)
     return pl.pallas_call(
-        functools.partial(_avg_pool_kernel, d=d),
-        grid=(B, Ho // rb, C // bc),
-        in_specs=[pl.BlockSpec((None, rb * d, W, bc),
-                               lambda b, i, c: (b, i, 0, c))],
-        out_specs=pl.BlockSpec((None, rb, Wo, bc),
-                               lambda b, i, c: (b, i, 0, c)),
-        out_shape=jax.ShapeDtypeStruct((B, Ho, Wo, C), x.dtype),
+        functools.partial(_avg_pool_kernel, taps=taps, stride=L // taps),
+        grid=(N // nb, Ho // rb),
+        in_specs=[pl.BlockSpec((nb, rb, L), lambda n, i: (n, i, 0)),
+                  pl.BlockSpec((cw, co), lambda n, i: (0, 0))],
+        out_specs=pl.BlockSpec((nb, rb, Wo), lambda n, i: (n, i, 0)),
+        out_shape=jax.ShapeDtypeStruct((N, Ho, Wo), x.dtype),
         interpret=interpret,
-    )(x)
+    )(x, pool)
 
 
-def nn_upsample_kernel(x: jnp.ndarray, d: int, *, rb: int = DEFAULT_RB,
-                       bc: int = DEFAULT_BC, interpret: bool = True):
+def nn_upsample_kernel(x: jnp.ndarray, d: int, *, rb: int, bc: int,
+                       interpret: bool):
     """x: (B, H, W, C) with H % rb == 0, C % bc == 0 -> (B, H*d, W*d, C)."""
     B, H, W, C = x.shape
     return pl.pallas_call(

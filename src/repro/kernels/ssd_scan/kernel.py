@@ -103,7 +103,7 @@ def _ssd_kernel(xbar_ref, dA_ref, b_ref, c_ref, s0_ref, y_ref, sfin_ref,
 
 def ssd_scan_kernel(xbar: jnp.ndarray, dA: jnp.ndarray, Bm: jnp.ndarray,
                     Cm: jnp.ndarray, s0: jnp.ndarray, *, chunk: int,
-                    bh: int = DEFAULT_BH, interpret: bool = True):
+                    bh: int = DEFAULT_BH, interpret: bool):
     """xbar: (b, T, H, P); dA: (b, T, H); Bm/Cm: (b, T, G, N);
     s0: (b, H, N, P) initial state.  T % chunk == 0; bh divides H and the
     group size H/G.  Returns (Y (b,T,H,P) f32, final_state (b,H,N,P) f32).

@@ -82,7 +82,7 @@ def _window_kernel_flagged(q_ref, k_ref, v_ref, f_ref, o_ref, *,
 def window_attention_kernel(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                             *, scale: float, w2_valid: int,
                             wb: int = DEFAULT_WB,
-                            interpret: bool = True,
+                            interpret: bool,
                             win_flags: jnp.ndarray = None) -> jnp.ndarray:
     """q: (BW, H, W2p, Dh); k/v: (BW, KV, W2p, Dh).  BW = batch*windows,
     BW % wb == 0, W2p % 8 == 0 (ops.py pads).  Returns q-shaped output.

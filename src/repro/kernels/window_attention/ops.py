@@ -99,6 +99,19 @@ _window_attention.defvjp(_vjp_fwd, _vjp_bwd)
 _entry = jax.jit(_window_attention, static_argnums=(4, 5, 6, 7))
 
 
+def resolve_wb(wb: int, BW: int) -> int:
+    """The window block the kernel runs for ``BW`` windows when ``wb`` is
+    asked for: the largest multiple of 8 that divides BW and is at most
+    max(wb, 8), else all of BW.  The flagged kernel's (wb, 1) flags block
+    needs a whole 8-row sublane tile or the full axis, so no other block
+    compiles on the TPU."""
+    want = max(8, min(int(wb), BW))
+    for cand in range(want - want % 8, 0, -8):
+        if BW % cand == 0:
+            return cand
+    return BW
+
+
 def window_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                      window: int, *, scale: Optional[float] = None,
                      win_valid: Optional[jnp.ndarray] = None,
@@ -123,9 +136,7 @@ def window_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
             "window_attention",
             autotune.window_bucket(B, T, H, Dh, window, q.dtype),
             {"wb": K.DEFAULT_WB})["wb"]
-    wb = min(int(wb), B * W)
-    while (B * W) % wb:
-        wb //= 2
+    wb = resolve_wb(wb, B * W)
 
     valid_f = None
     if win_valid is not None:

@@ -132,7 +132,6 @@ class ServerModel:
                  b_buckets: Tuple[int, ...] = pt.BATCH_BUCKETS,
                  device_cache: bool = True,
                  n_length_buckets: int = pt.N_LENGTH_BUCKETS,
-                 donate_frames: bool = True,
                  quant=None, calib_frames=None):
         # ``quant``: optional quant.ptq.QuantSpec — compress the float
         # tree (int8 / half-cast / head-pruned) BEFORE any executable is
@@ -158,11 +157,6 @@ class ServerModel:
         self.score_thresh = score_thresh
         self.backend = backend
         self.jit = jit
-        # donate the staged frame buffer to the executable so XLA can
-        # reuse it as scratch — each wave stages a fresh array, so the
-        # buffer is never read again.  CPU XLA ignores donation (and
-        # warns), so the gate keeps the host path quiet.
-        self._donate = donate_frames and jax.default_backend() != "cpu"
         self.n_buckets = n_buckets
         self.b_buckets = tuple(sorted(b_buckets))
         self.device_cache = device_cache
@@ -289,11 +283,10 @@ class ServerModel:
                 # The executable can never silently retrace, so each
                 # cache miss is exactly one XLA compile — the telemetry
                 # below is the whole compile surface.
-                fn = jax.jit(
-                    fn,
-                    donate_argnums=(1,) if self._donate else ()).lower(
+                t0 = time.perf_counter()
+                fn = jax.jit(fn).lower(
                     self.params, *self._arg_structs(lb, batch)).compile()
-                self.stats.note_compile(key)
+                self.stats.note_compile(key, time.perf_counter() - t0)
             self._fns[key] = fn
         return self._fns[key]
 

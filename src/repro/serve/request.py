@@ -14,7 +14,7 @@ bucket reuse spans.
 from __future__ import annotations
 
 from dataclasses import dataclass, field
-from typing import List, Optional, Tuple
+from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
@@ -42,6 +42,8 @@ class ServingStats:
     compiles: int = 0
     steady_compiles: int = 0
     steady_compile_keys: List[Tuple] = field(default_factory=list)
+    # host seconds each executable took to lower and compile, by key
+    compile_s: Dict[Tuple, float] = field(default_factory=dict)
     warmed: bool = False
     warmup_wall_s: float = 0.0
     offloads: int = 0
@@ -63,10 +65,11 @@ class ServingStats:
     def tile_bytes_per_offload(self) -> float:
         return self.tile_bytes / max(self.offloads, 1)
 
-    def note_compile(self, key: Tuple) -> None:
-        """Record one executable compile; after warmup it counts as a
-        steady-state stall."""
+    def note_compile(self, key: Tuple, seconds: float = 0.0) -> None:
+        """Record one executable compile (``seconds`` of host time); after
+        warmup it counts as a steady-state stall."""
         self.compiles += 1
+        self.compile_s[key] = seconds
         if self.warmed:
             self.steady_compiles += 1
             self.steady_compile_keys.append(key)

@@ -10,6 +10,7 @@ import pytest
 
 from repro.kernels import autotune
 from repro.kernels.window_attention import kernel as wk
+from repro.kernels.window_attention import ops as wops
 
 
 @pytest.fixture()
@@ -71,13 +72,32 @@ def test_tune_skips_invalid_and_failing_candidates(tmp_cache):
             return boom
         return lambda: jnp.zeros((1,))
 
-    won = autotune.tune("fake2", "b=1", ({"x": 1}, {"x": 2}, {"x": 3}),
-                        bench, force=True, reps=1)
+    with pytest.warns(RuntimeWarning, match=r"candidate \{'x': 2\} "
+                      r"failed: RuntimeError: lowering failed"):
+        won = autotune.tune("fake2", "b=1", ({"x": 1}, {"x": 2}, {"x": 3}),
+                            bench, force=True, reps=1)
     assert won == {"x": 3}
     # all candidates invalid -> no winner, nothing cached
     assert autotune.tune("fake3", "b=1", ({"x": 1},), bench,
                          force=True) is None
     assert autotune.lookup("fake3", "b=1") is None
+
+
+def test_tune_raises_when_default_fails(tmp_cache):
+    """A failing default is the block every untuned executable would
+    run, so the sweep raises instead of reporting and moving on."""
+    def bench(params):
+        if params["x"] == 1:
+            def boom():
+                raise RuntimeError("vmem exhausted")
+            return boom
+        return lambda: jnp.zeros((1,))
+
+    with pytest.raises(RuntimeError, match="kernel default") as info:
+        autotune.tune("fake5", "b=1", ({"x": 1}, {"x": 2}), bench,
+                      default={"x": 1}, force=True, reps=1)
+    assert "vmem exhausted" in str(info.value.__cause__)
+    assert autotune.lookup("fake5", "b=1") is None
 
 
 def test_autotune_disabled_env(tmp_cache, monkeypatch):
@@ -102,12 +122,25 @@ def test_autotune_disabled_env(tmp_cache, monkeypatch):
     assert autotune.enabled()
 
 
+@pytest.mark.parametrize("wb", [1, 4, 8, 16, 32, 64])
+def test_resolved_window_block_is_a_sublane_tile(wb):
+    """Whatever block is asked for (a tuned winner, a caller's ``wb``),
+    the kernel runs a divisor of BW that is a multiple of 8 or all of
+    BW — the only blocks the flagged kernel compiles with on the TPU."""
+    for c in autotune.WINDOW_CANDIDATES:
+        assert c["wb"] % 8 == 0
+    for BW in range(1, 161):
+        got = wops.resolve_wb(wb, BW)
+        assert BW % got == 0
+        assert got % 8 == 0 or got == BW, (wb, BW, got)
+
+
 def test_tune_window_end_to_end(tmp_cache):
     """The real window-attention sweep under force: records a winner the
     ops.py wb=None path then resolves (and the kernel still validates)."""
-    won = autotune.tune_window(1, 64, 2, 16, 16, force=True)
+    won = autotune.tune_window(2, 128, 2, 16, 16, force=True)
     assert won is not None and "wb" in won
-    bucket = autotune.window_bucket(1, 64, 2, 16, 16, jnp.float32)
+    bucket = autotune.window_bucket(2, 128, 2, 16, 16, jnp.float32)
     assert autotune.block("window_attention", bucket,
                           {"wb": wk.DEFAULT_WB})["wb"] == won["wb"]
     # shape-bucketed: a different shape misses and falls back

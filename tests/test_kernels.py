@@ -101,7 +101,7 @@ def test_window_attention_win_valid_boundaries(valid):
     k = _rand(ks[1], (B, T, H, Dh), jnp.float32)
     v = _rand(ks[2], (B, T, H, Dh), jnp.float32)
     wv = jnp.asarray([valid, max(valid - 1, 0)], jnp.int32)
-    out = window_attention(q, k, v, win, win_valid=wv, wb=4)
+    out = window_attention(q, k, v, win, win_valid=wv, wb=8)
     ref = window_attention_ref(q, k, v, win)
     keep = (jnp.arange(W)[None, :] < wv[:, None]).astype(ref.dtype)
     ref = ref * jnp.repeat(keep, win, axis=1)[:, :, None, None]
