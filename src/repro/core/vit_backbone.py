@@ -35,6 +35,7 @@ from repro.models import attention as attn
 from repro.models import layers as L
 from repro.models.config import ModelConfig
 from repro.quant import qtensor as qt
+from repro.spans import HEAD, POST_BETA, PRE_BETA
 
 
 def vit_partition(cfg: ModelConfig) -> Partition:
@@ -228,6 +229,32 @@ def _vit_block(cfg: ModelConfig, p, x, *, window: int,
     return x + L.apply_mlp(cfg, p["ffn"], h)
 
 
+def _restore(cfg: ModelConfig, part: Partition, tokens, fused: bool,
+             padded: bool, layout, full_ids, low_ids, reuse_ids,
+             reuse_tiles, backend):
+    """Restore the mixed sequence to full length at the restoration
+    point, splicing REUSE regions from ``reuse_tiles``."""
+    w2 = part.window * part.window
+    if fused:
+        B, D = tokens.shape[0], tokens.shape[-1]
+        return dispatch.fused_restore(
+            tokens.reshape(B, -1, w2, D), layout["out_src"],
+            layout["out_map"], part.window, part.downsample,
+            reuse_tiles=reuse_tiles)
+    if padded:
+        return mr.restore_padded(
+            tokens, part, layout["win_dst"], layout["low_src"],
+            layout["low_ids"], backend=backend,
+            reuse_ids=(layout["reuse_ids"]
+                       if reuse_tiles is not None else None),
+            reuse_tiles=reuse_tiles)
+    n_reuse = 0 if reuse_ids is None else reuse_ids.shape[-1]
+    return mr.restore_full(
+        tokens, part, full_ids, low_ids, backend=backend,
+        reuse_ids=(reuse_ids if n_reuse else None),
+        reuse_tiles=(reuse_tiles if n_reuse else None))
+
+
 def forward_features(cfg: ModelConfig, params, image: jnp.ndarray,
                      full_ids: Optional[jnp.ndarray] = None,
                      low_ids: Optional[jnp.ndarray] = None,
@@ -306,72 +333,78 @@ def forward_features(cfg: ModelConfig, params, image: jnp.ndarray,
         assert capture_beta >= beta, \
             "cannot capture tiles before the restoration point"
 
-    x_full = embed_patches(cfg, params, image, backend=backend)  # B,Hp,Wp,D
-    pos = qt.asarray(params["pos_emb"])
-    kv_len = win_valid = None
-    # fused serving lane (kernels.fused_serving): pack + pos-embed +
-    # pad zeroing fold into one prologue kernel and the restoration
-    # scatter into one destination-major gather epilogue, so the packed
-    # activations never round-trip HBM between the stages.  Engages on
-    # the Pallas backend when the layout carries the inverse maps
-    # (PlanLayout.out_src/out_map); legacy layout dicts fall back to the
-    # unfused path unchanged.
-    fused = (padded and beta >= 1 and "out_src" in layout
-             and dispatch.use_pallas(backend))
-    if padded:
-        # the collapsed executable serves every plan mix, so the pooled
-        # grid is always packed (a reuse-only sample simply never
-        # gathers from the low half of the window bank)
-        x_low = embed_patches(cfg, params, image, part.downsample, backend)
-        if fused:
-            # pad windows come out zero rather than window-0 replicas:
-            # window attention zeroes them anyway, global attention
-            # masks them via kv_len, restoration never reads them — the
-            # valid lanes are bit-identical to the unfused pack
-            bank = mr.window_bank(x_full, part, x_low, backend=backend)
-            tokens = dispatch.fused_pack_pos(bank,
-                                             pos_window_bank(pos, part),
-                                             layout["win_src"],
-                                             layout["nw"])
-        else:
-            tokens = mr.pack_padded(x_full, part, layout["win_src"],
-                                    x_low_grid=x_low, backend=backend)
-        if beta == 0:                     # restore at input: full length
-            tokens = mr.restore_padded(tokens, part, layout["win_dst"],
-                                       layout["low_src"],
-                                       layout["low_ids"],
-                                       backend=backend)
-            tokens = tokens + packed_positions(pos, part, None, None)
-        else:
-            if not fused:
-                tokens = tokens + packed_positions(
-                    pos, part, None, None, win_src=layout["win_src"],
-                    ids_key=ids_key)
-            win_valid = jnp.asarray(layout["nw"], jnp.int32)
-            kv_len = win_valid * w2
-            if win_valid.ndim == 0:
-                win_valid = win_valid[None]
-                kv_len = kv_len[None]
-    elif mixed:
-        # reuse-only plans (n_low = 0) never read the pooled grid — skip
-        # the downsampled patch-embedding pass entirely
-        x_low = (embed_patches(cfg, params, image, part.downsample,
-                               backend) if has_low else None)
-        tokens, _ = mr.pack_mixed(x_full, part, full_ids, low_ids,
-                                  x_low_grid=x_low, backend=backend)
-        tokens = tokens + packed_positions(pos, part, full_ids, low_ids,
-                                           ids_key=ids_key)
-    else:
-        if has_low:                                           # beta == 0
-            x_low = embed_patches(cfg, params, image, part.downsample,
-                                  backend)
-            packed, _ = mr.pack_mixed(x_full, part, full_ids, low_ids,
+    # the device step in named scopes (HLO metadata only): vit.pre_beta
+    # up to the restoration point, vit.post_beta from it on.  A
+    # full-resolution forward splits at its capture point.
+    split = beta if mixed else capture_beta
+    with jax.named_scope(PRE_BETA):
+        x_full = embed_patches(cfg, params, image,
+                               backend=backend)         # B,Hp,Wp,D
+        pos = qt.asarray(params["pos_emb"])
+        kv_len = win_valid = None
+        # fused serving lane (kernels.fused_serving): pack + pos-embed +
+        # pad zeroing fold into one prologue kernel and the restoration
+        # scatter into one destination-major gather epilogue, so the packed
+        # activations never round-trip HBM between the stages.  Engages on
+        # the Pallas backend when the layout carries the inverse maps
+        # (PlanLayout.out_src/out_map); legacy layout dicts fall back to the
+        # unfused path unchanged.
+        fused = (padded and beta >= 1 and "out_src" in layout
+                 and dispatch.use_pallas(backend))
+        if padded:
+            # the collapsed executable serves every plan mix, so the pooled
+            # grid is always packed (a reuse-only sample simply never
+            # gathers from the low half of the window bank)
+            x_low = embed_patches(cfg, params, image, part.downsample, backend)
+            if fused:
+                # pad windows come out zero rather than window-0 replicas:
+                # window attention zeroes them anyway, global attention
+                # masks them via kv_len, restoration never reads them — the
+                # valid lanes are bit-identical to the unfused pack
+                bank = mr.window_bank(x_full, part, x_low, backend=backend)
+                tokens = dispatch.fused_pack_pos(bank,
+                                                 pos_window_bank(pos, part),
+                                                 layout["win_src"],
+                                                 layout["nw"])
+            else:
+                tokens = mr.pack_padded(x_full, part, layout["win_src"],
+                                        x_low_grid=x_low, backend=backend)
+            if beta == 0:                     # restore at input: full length
+                tokens = mr.restore_padded(tokens, part, layout["win_dst"],
+                                           layout["low_src"],
+                                           layout["low_ids"],
+                                           backend=backend)
+                tokens = tokens + packed_positions(pos, part, None, None)
+            else:
+                if not fused:
+                    tokens = tokens + packed_positions(
+                        pos, part, None, None, win_src=layout["win_src"],
+                        ids_key=ids_key)
+                win_valid = jnp.asarray(layout["nw"], jnp.int32)
+                kv_len = win_valid * w2
+                if win_valid.ndim == 0:
+                    win_valid = win_valid[None]
+                    kv_len = kv_len[None]
+        elif mixed:
+            # reuse-only plans (n_low = 0) never read the pooled grid — skip
+            # the downsampled patch-embedding pass entirely
+            x_low = (embed_patches(cfg, params, image, part.downsample,
+                                   backend) if has_low else None)
+            tokens, _ = mr.pack_mixed(x_full, part, full_ids, low_ids,
                                       x_low_grid=x_low, backend=backend)
-            tokens = mr.restore_full(packed, part, full_ids, low_ids,
-                                     backend=backend)
+            tokens = tokens + packed_positions(pos, part, full_ids, low_ids,
+                                               ids_key=ids_key)
         else:
-            tokens = mr.grid_to_full_seq(x_full, part)
-        tokens = tokens + packed_positions(pos, part, None, None)
+            if has_low:                                           # beta == 0
+                x_low = embed_patches(cfg, params, image, part.downsample,
+                                      backend)
+                packed, _ = mr.pack_mixed(x_full, part, full_ids, low_ids,
+                                          x_low_grid=x_low, backend=backend)
+                tokens = mr.restore_full(packed, part, full_ids, low_ids,
+                                         backend=backend)
+            else:
+                tokens = mr.grid_to_full_seq(x_full, part)
+            tokens = tokens + packed_positions(pos, part, None, None)
 
     tiles = None
     restored = not mixed
@@ -380,41 +413,30 @@ def forward_features(cfg: ModelConfig, params, image: jnp.ndarray,
             idx = s * M + m
             params_blk = params["blocks"][idx]
             is_global = m == M - 1
+            stage = PRE_BETA if idx < split * M - 1 else POST_BETA
             if is_global and not restored and beta == s + 1:
-                if fused:
-                    B, D = tokens.shape[0], tokens.shape[-1]
-                    tokens = dispatch.fused_restore(
-                        tokens.reshape(B, -1, w2, D), layout["out_src"],
-                        layout["out_map"], part.window, part.downsample,
-                        reuse_tiles=reuse_tiles)
-                elif padded:
-                    tokens = mr.restore_padded(
-                        tokens, part, layout["win_dst"],
-                        layout["low_src"], layout["low_ids"],
-                        backend=backend,
-                        reuse_ids=(layout["reuse_ids"]
-                                   if reuse_tiles is not None else None),
-                        reuse_tiles=reuse_tiles)
-                else:
-                    tokens = mr.restore_full(
-                        tokens, part, full_ids, low_ids, backend=backend,
-                        reuse_ids=(reuse_ids if n_reuse else None),
-                        reuse_tiles=(reuse_tiles if n_reuse else None))
+                with jax.named_scope(stage), jax.named_scope("restore"):
+                    tokens = _restore(cfg, part, tokens, fused, padded,
+                                      layout, full_ids, low_ids, reuse_ids,
+                                      reuse_tiles, backend)
                 restored = True
             if is_global and capture_beta == s + 1:
                 B = tokens.shape[0]
                 tiles = tokens.reshape(B, part.n_regions,
                                        part.windows_per_full_region,
                                        w2, tokens.shape[-1])
-            tokens = _vit_block(cfg, params_blk, tokens,
-                                window=0 if is_global else w2,
-                                kv_len=None if restored else kv_len,
-                                win_valid=None if restored else win_valid,
-                                backend=backend)
+            with jax.named_scope(stage), jax.named_scope(f"block{idx:02d}"):
+                tokens = _vit_block(cfg, params_blk, tokens,
+                                    window=0 if is_global else w2,
+                                    kv_len=None if restored else kv_len,
+                                    win_valid=(None if restored
+                                               else win_valid),
+                                    backend=backend)
     # beta <= N always restores: beta == N hits the LAST global block.
 
-    tokens = L.apply_norm(cfg, params["final_norm"], tokens)
-    feats = mr.full_seq_to_grid(tokens, part)
+    with jax.named_scope(POST_BETA):
+        tokens = L.apply_norm(cfg, params["final_norm"], tokens)
+        feats = mr.full_seq_to_grid(tokens, part)
     if capture_beta:
         return feats, tiles
     return feats
@@ -438,8 +460,9 @@ def forward_det(cfg: ModelConfig, params, image,
                              ids_key=ids_key)
     if capture_beta:
         feats, tiles = feats
-        return dh.det_head_forward(cfg, params["head"], feats), tiles
-    return dh.det_head_forward(cfg, params["head"], feats)
+    with jax.named_scope(HEAD):
+        outs = dh.det_head_forward(cfg, params["head"], feats)
+    return (outs, tiles) if capture_beta else outs
 
 
 # ---------------------------------------------------------------------------

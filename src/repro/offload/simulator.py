@@ -21,6 +21,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import spans
 from repro.core import mixed_res as mr
 from repro.core import partition as pt
 from repro.core import vit_backbone as vb
@@ -72,6 +73,7 @@ class StagedWave:
     of wave N+1 overlaps wave N's compute under JAX async dispatch."""
     B: int                   # real rows; imgs carries Bp >= B
     imgs: jnp.ndarray
+    wave: Optional[spans.Wave] = None     # while spans are recorded
 
 
 @dataclass
@@ -85,12 +87,29 @@ class PendingWave:
     classes: jnp.ndarray
     B: int
     score_thresh: float
+    wave: Optional[spans.Wave] = None     # while spans are recorded
 
     def wait(self) -> List[List[Dict]]:
-        return [det.detections_from_arrays(
-                    self.boxes[i], self.scores[i], self.classes[i],
-                    self.score_thresh)
-                for i in range(self.B)]
+        # Each row and array is read through a row slice (dynamic_slice
+        # and squeeze: two device computations).  The first row's slices
+        # are dispatched before anything blocks: slices dispatched after
+        # a block on the whole outputs queue behind any wave launched
+        # since, and the reads then wait out its forward too.
+        with spans.span("serve.wait", self.wave):
+            with spans.span("serve.ready") as sp:
+                first = (self.boxes[0], self.scores[0], self.classes[0])
+                jax.block_until_ready(first)
+                sp.add("jit_launches", 6)
+            with spans.span("serve.decode") as sp:
+                dets = [det.detections_from_arrays(*first,
+                                                   self.score_thresh)]
+                dets += [det.detections_from_arrays(
+                             self.boxes[i], self.scores[i], self.classes[i],
+                             self.score_thresh)
+                         for i in range(1, self.B)]
+                sp.add("jit_launches", 6 * (self.B - 1))
+                sp.add("d2h", 3 * self.B)
+        return dets
 
 
 class ServerModel:
@@ -223,10 +242,12 @@ class ServerModel:
         cfg, backend = self.cfg, self.backend
 
         def finish(outs):
+            tiles = None
             if capture:
                 outs, tiles = outs
-                return self._decode(outs), tiles
-            return self._decode(outs)
+            with jax.named_scope(spans.HEAD):
+                dets = self._decode(outs)
+            return (dets, tiles) if capture else dets
 
         if lb == 0:
             def fn(params, img):
@@ -282,11 +303,16 @@ class ServerModel:
                 # AOT: lower + compile against the key's exact shapes.
                 # The executable can never silently retrace, so each
                 # cache miss is exactly one XLA compile — the telemetry
-                # below is the whole compile surface.
+                # below is the whole compile surface.  One HLO module
+                # name per key, so a device trace's module events say
+                # which executable ran (spans.scope_map).
+                name = f"serve_lb{lb}_beta{beta}_cap{capture}_b{batch}"
+                fn.__name__ = fn.__qualname__ = name
                 t0 = time.perf_counter()
                 fn = jax.jit(fn).lower(
                     self.params, *self._arg_structs(lb, batch)).compile()
                 self.stats.note_compile(key, time.perf_counter() - t0)
+                spans.note_executable(name, fn)
             self._fns[key] = fn
         return self._fns[key]
 
@@ -447,13 +473,16 @@ class ServerModel:
         previous wave still computes, the transfer overlaps it.  The
         result feeds :meth:`infer_wave` in place of the host array.
         """
-        frames = np.asarray(frames)
-        B = frames.shape[0]
-        npad = self.batch_bucket(B) - B
-        if npad:
-            frames = np.concatenate(
-                [frames, np.repeat(frames[:1], npad, axis=0)])
-        return StagedWave(B=B, imgs=jax.device_put(frames))
+        with spans.span("serve.stage", spans.NEW) as sp:
+            frames = np.asarray(frames)
+            B = frames.shape[0]
+            npad = self.batch_bucket(B) - B
+            if npad:
+                frames = np.concatenate(
+                    [frames, np.repeat(frames[:1], npad, axis=0)])
+            imgs = jax.device_put(frames)
+            sp.add("h2d")
+        return StagedWave(B=B, imgs=imgs, wave=sp.wave)
 
     def infer_wave(self, frames, plans: Sequence[RegionPlan],
                    beta: int = 0,
@@ -462,7 +491,8 @@ class ServerModel:
                    frame_ids: Optional[Sequence[int]] = None,
                    capture_beta: int = 0,
                    lb_override: Optional[int] = None,
-                   defer: bool = False):
+                   defer: bool = False,
+                   sessions: Optional[Sequence[int]] = None):
         """Serve one wave (B >= 1 frames) through the collapsed grid.
 
         frames: (B, H, W, 3); plans: per-sample RegionPlans — ANY
@@ -484,7 +514,30 @@ class ServerModel:
         array from :meth:`stage_frames`) and ``defer=True`` returns a
         :class:`PendingWave` instead of decoded detections — together
         the continuous scheduler's async-overlap path.
+
+        ``sessions``: the caller's session id of each row; with
+        ``frame_ids`` they are the wave's offload ids on its spans (-1
+        where not given).
         """
+        staged = frames if isinstance(frames, StagedWave) else None
+        with spans.span("serve.infer_wave",
+                        staged.wave if staged and staged.wave
+                        else spans.NEW) as sp:
+            if sp.wave is not None:
+                none = [-1] * len(plans)
+                sp.wave.offloads = tuple(zip(
+                    none if sessions is None else sessions,
+                    none if frame_ids is None else frame_ids))
+            pending = self._dispatch(frames, plans, beta, caches,
+                                     frame_ids, capture_beta, lb_override,
+                                     sp)
+        return pending if defer else pending.wait()
+
+    def _dispatch(self, frames, plans: Sequence[RegionPlan], beta: int,
+                  caches, frame_ids, capture_beta: int,
+                  lb_override: Optional[int], sp) -> PendingWave:
+        """:meth:`infer_wave` up to the executable's launch and the cache
+        refresh; ``sp`` is the wave's ``serve.infer_wave`` span."""
         staged: Optional[StagedWave] = None
         if isinstance(frames, StagedWave):
             staged = frames
@@ -534,12 +587,15 @@ class ServerModel:
             imgs = staged.imgs
         else:
             imgs = jnp.asarray(pad_rows(frames))
+            sp.add("h2d")
         layouts: Optional[List[pt.PlanLayout]] = None
         if full_res and lb_override is None:
             store_cap = capture_beta if caches is not None else 0
             exec_cap = self._full_cap(store_cap)
             fn = self._get_fn(0, 0, exec_cap, Bp)
-            out = fn(self.params, imgs)
+            with spans.span("serve.launch") as launch:
+                out = fn(self.params, imgs)
+                launch.add("launches")
         else:
             # beta == 0 with a mixed plan is the paper's restore-at-
             # input case (full-length compute, upsampled input) — it has
@@ -552,19 +608,24 @@ class ServerModel:
             assert lb >= max(nws) and lb in self.length_edges, \
                 f"lb_override {lb} cannot hold {max(nws)} windows " \
                 f"(edges {self.length_edges})"
-            layouts = [pt.plan_layout(p.states, lb, self.part)
-                       for p in plans]
-            arrays, wave_key = pt.stack_plan_layouts(layouts)
+            with spans.span("serve.layout"):
+                layouts = [pt.plan_layout(p.states, lb, self.part)
+                           for p in plans]
+                arrays, wave_key = pt.stack_plan_layouts(layouts)
             tiles_in = self._wave_tiles(layouts, caches, npad)
             # mixed execs always capture at their restoration point;
             # beta_eff == 0 has none, so it never captures
             exec_cap = beta_eff
             store_cap = beta_eff if caches is not None else 0
             fn = self._get_fn(lb, beta_eff, exec_cap, Bp)
-            args = [jnp.asarray(pad_rows(arrays[k]))
-                    for k in _LAYOUT_ARGS]
+            with spans.span("serve.args") as put:
+                args = [jnp.asarray(pad_rows(arrays[k]))
+                        for k in _LAYOUT_ARGS]
+                put.add("h2d", len(args))
             kw = {} if self.jit else {"ids_key": wave_key}
-            out = fn(self.params, imgs, *args, tiles_in, **kw)
+            with spans.span("serve.launch") as launch:
+                out = fn(self.params, imgs, *args, tiles_in, **kw)
+                launch.add("launches")
 
         if exec_cap:
             (boxes, scores, classes), tiles_out = out
@@ -576,9 +637,8 @@ class ServerModel:
         else:
             boxes, scores, classes = out
         self.stats.offloads += B
-        pending = PendingWave(boxes, scores, classes, B,
-                              self.score_thresh)
-        return pending if defer else pending.wait()
+        return PendingWave(boxes, scores, classes, B, self.score_thresh,
+                           sp.wave)
 
     def _zeros_tiles(self, Bp: int) -> jnp.ndarray:
         """Cached all-zero reuse-tiles input for reuse-free waves (a
@@ -602,60 +662,75 @@ class ServerModel:
         the sentinel.  Device-resident caches stack on device — zero
         h2d tile bytes; host caches are uploaded (and accounted) here.
         """
-        B = len(layouts)
-        if caches is None or all(l.n_reuse == 0 for l in layouts):
-            return self._zeros_tiles(B + npad)
-        part = self.part
-        tile = (part.n_regions, part.windows_per_full_region,
-                part.tokens_low_region, self.cfg.d_model)
-        gathered, host_bytes = [], 0
-        for l, c in zip(layouts, caches):
-            if l.n_reuse == 0 or c is None or c.tiles is None:
-                gathered.append(None)
-                continue
-            ids = np.where(l.reuse_ids < part.n_regions, l.reuse_ids, 0)
-            g = c.gather(ids)
-            if isinstance(g, np.ndarray):
-                # only the real rows are payload; the clipped pad rows
-                # are an artifact of the padded gather
-                host_bytes += g[:l.n_reuse].nbytes
-            gathered.append(g)
-        if host_bytes == 0:
-            rows = [g if g is not None
-                    else jnp.zeros(tile, self.act_dtype)
+        with spans.span("serve.tiles") as sp:
+            B = len(layouts)
+            if caches is None or all(l.n_reuse == 0 for l in layouts):
+                if B + npad not in self._zero_tiles:
+                    sp.add("jit_launches", 2)      # zeros: convert, broadcast
+                return self._zeros_tiles(B + npad)
+            part = self.part
+            tile = (part.n_regions, part.windows_per_full_region,
+                    part.tokens_low_region, self.cfg.d_model)
+            gathered, host_bytes = [], 0
+            for l, c in zip(layouts, caches):
+                if l.n_reuse == 0 or c is None or c.tiles is None:
+                    gathered.append(None)
+                    continue
+                ids = np.where(l.reuse_ids < part.n_regions, l.reuse_ids, 0)
+                g = c.gather(ids)
+                if isinstance(g, np.ndarray):
+                    # only the real rows are payload; the clipped pad rows
+                    # are an artifact of the padded gather
+                    host_bytes += g[:l.n_reuse].nbytes
+                else:
+                    sp.add("h2d")                  # the ids
+                    sp.add("jit_launches")         # gather_tiles
+                gathered.append(g)
+            if host_bytes == 0:
+                rows = [g if g is not None
+                        else jnp.zeros(tile, self.act_dtype)
+                        for g in gathered]
+                rows += [rows[0]] * npad
+                # each zero row is two computations; jnp.stack expands
+                # every row and concatenates them
+                sp.add("jit_launches", 2 * sum(g is None for g in gathered)
+                       + len(rows) + 1)
+                return jnp.stack(rows)
+            self.stats.tile_bytes_h2d += host_bytes
+            rows = [np.asarray(g) if g is not None
+                    else np.zeros(tile, np.dtype(self.act_dtype))
                     for g in gathered]
             rows += [rows[0]] * npad
-            return jnp.stack(rows)
-        self.stats.tile_bytes_h2d += host_bytes
-        rows = [np.asarray(g) if g is not None
-                else np.zeros(tile, np.dtype(self.act_dtype))
-                for g in gathered]
-        rows += [rows[0]] * npad
-        return jnp.asarray(np.stack(rows))
+            sp.add("h2d")
+            return jnp.asarray(np.stack(rows))
 
     def _refresh_caches(self, caches, tiles_out, layouts, cap: int,
                         frame_ids) -> None:
         """Refresh each real sessionful sample's cache with its captured
         tiles.  Padded rows and cache-less samples are never written."""
-        B = len(caches)
-        reuse_rows = [l.reuse_ids[:l.n_reuse] if l is not None
-                      else np.zeros((0,), np.int32)
-                      for l in (layouts or [None] * B)]
-        if self.device_cache:
-            for i, c in enumerate(caches[:B]):
-                if c is None:
-                    continue
-                c.update(mr.take_sample_tiles(tiles_out, np.int32(i)),
-                         reuse_rows[i], cap, frame_ids[i],
-                         epoch=self.epoch)
-        else:
-            tiles_np = np.asarray(tiles_out)
-            live = [i for i, c in enumerate(caches[:B]) if c is not None]
-            self.stats.tile_bytes_d2h += sum(tiles_np[i].nbytes
-                                             for i in live)
-            for i in live:
-                caches[i].update(tiles_np[i], reuse_rows[i], cap,
-                                 frame_ids[i], epoch=self.epoch)
+        with spans.span("serve.cache_refresh") as sp:
+            B = len(caches)
+            reuse_rows = [l.reuse_ids[:l.n_reuse] if l is not None
+                          else np.zeros((0,), np.int32)
+                          for l in (layouts or [None] * B)]
+            if self.device_cache:
+                for i, c in enumerate(caches[:B]):
+                    if c is None:
+                        continue
+                    tiles = mr.take_sample_tiles(tiles_out, np.int32(i))
+                    # the take, and the refresh where it overwrites in place
+                    sp.add("jit_launches", 1 + c.refreshes_in_place(tiles))
+                    c.update(tiles, reuse_rows[i], cap, frame_ids[i],
+                             epoch=self.epoch)
+            else:
+                tiles_np = np.asarray(tiles_out)
+                sp.add("d2h")
+                live = [i for i, c in enumerate(caches[:B]) if c is not None]
+                self.stats.tile_bytes_d2h += sum(tiles_np[i].nbytes
+                                                 for i in live)
+                for i in live:
+                    caches[i].update(tiles_np[i], reuse_rows[i], cap,
+                                     frame_ids[i], epoch=self.epoch)
 
     # ------------------------------------------------------------------
     # speculative REUSE execution (the spliced forward starts before the

@@ -261,6 +261,14 @@ class FeatureCache:
             # decoded canvas right after the refresh) resets it
             self.pred_age += 1
 
+    def refreshes_in_place(self, tiles) -> bool:
+        """Would :meth:`update` with these device tiles overwrite the
+        held buffer in place (one donated ``refresh_tiles`` call)?"""
+        return (not isinstance(tiles, np.ndarray) and self.owns_tiles
+                and self.tiles_on_device
+                and self.tiles.shape == tiles.shape
+                and self.tiles.dtype == tiles.dtype)
+
     def update(self, tiles, reuse_ids: np.ndarray,
                beta: int, frame: int, epoch: Optional[int] = None) -> None:
         """Full refresh after a forward that captured tiles.
@@ -274,9 +282,7 @@ class FeatureCache:
         if isinstance(tiles, np.ndarray):
             self.tiles = tiles
         else:
-            if (self.owns_tiles and self.tiles_on_device
-                    and self.tiles.shape == tiles.shape
-                    and self.tiles.dtype == tiles.dtype):
+            if self.refreshes_in_place(tiles):
                 from repro.core import mixed_res as mr
                 self.tiles = mr.refresh_tiles(self.tiles, tiles)
             else:

@@ -8,6 +8,8 @@ cannot be (never at import: the test workers must collect the same
 tests).  Every entry point is called with ``interpret=False``, since
 off-TPU the ops.py default would interpret."""
 import os
+import re
+from contextlib import nullcontext
 
 import jax
 import jax.numpy as jnp
@@ -119,3 +121,28 @@ def test_kernel_compiles_for_v5e(case, one_chip):
     fn, *args = CASES[case](struct)
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_named_scopes_keep_kernel_instruction_names(one_chip):
+    """The device scopes (repro.spans.SCOPES) are HLO metadata only: the
+    attention kernels keep the instruction names the benchmark's roofline
+    readers match (``_flash_attention*``, ``_window_attention*``)."""
+    q = jax.ShapeDtypeStruct((1, T, H, DH), jnp.float32, sharding=one_chip)
+
+    def step(q, scoped):
+        def scope(name):
+            return jax.named_scope(name) if scoped else nullcontext()
+        with scope("vit.pre_beta"):
+            a = window_attention(q, q, q, W2, interpret=False)
+        with scope("vit.post_beta"):
+            return flash_attention(a, a, a, interpret=False)
+
+    names = {}
+    for scoped in (True, False):
+        text = jax.jit(lambda q: step(q, scoped)).lower(q).compile().as_text()
+        names[scoped] = sorted(re.findall(r"%(_(?:flash|window)_attention"
+                                          r"[.\w]*) = ", text))
+        assert ("vit.post_beta" in text) is scoped
+    assert names[True] == names[False]
+    assert [n.split(".")[0] for n in names[True]] == ["_flash_attention",
+                                                     "_window_attention"]
