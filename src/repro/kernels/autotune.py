@@ -43,11 +43,13 @@ import numpy as np
 ENV_VAR = "REPRO_AUTOTUNE"
 CACHE_ENV = "REPRO_AUTOTUNE_CACHE"
 
-# candidate grids per kernel; first entry is never assumed — the fixed
-# ops.py default is always a candidate so tuning can only tie or win.
+# candidate grids per kernel; first entry is never assumed — the block
+# a kernel runs untuned is always a candidate so tuning can only tie or
+# win (flash: kernel.default_blocks of the shape, added when missing).
 FLASH_CANDIDATES = ({"bq": 128, "bk": 128}, {"bq": 128, "bk": 256},
                     {"bq": 256, "bk": 256}, {"bq": 256, "bk": 512},
-                    {"bq": 512, "bk": 512})
+                    {"bq": 512, "bk": 512}, {"bq": 512, "bk": 1024},
+                    {"bq": 1024, "bk": 1024})
 # window blocks stay whole sublane tiles (window_attention.ops.resolve_wb)
 WINDOW_CANDIDATES = ({"wb": 8}, {"wb": 16}, {"wb": 32})
 DECODE_CANDIDATES = ({"bs": 256}, {"bs": 512}, {"bs": 1024})
@@ -309,15 +311,19 @@ def tune_flash(B: int, T: int, S: int, H: int, Dh: int, *,
     k = jax.random.normal(rng, (B, S, KV, Dh), dtype)
     v = jax.random.normal(rng, (B, S, KV, Dh), dtype)
 
+    bq, bk = _fk.default_blocks(T, S, causal)
+    default = {"bq": bq, "bk": bk}
+    candidates = FLASH_CANDIDATES
+    if default not in candidates:       # a ragged length's own blocks
+        candidates += (default,)
+
     def bench(params):
         return lambda: _flash.flash_attention(
             q, k, v, causal=causal, bq=params["bq"], bk=params["bk"])
 
     return tune("flash_attention",
                 flash_bucket(B, T, S, H, KV, Dh, causal, dtype),
-                FLASH_CANDIDATES, bench,
-                default={"bq": _fk.DEFAULT_BQ, "bk": _fk.DEFAULT_BK},
-                force=force)
+                candidates, bench, default=default, force=force)
 
 
 def tune_decode(B: int, S: int, H: int, Dh: int, *,

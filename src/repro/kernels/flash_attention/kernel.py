@@ -13,10 +13,24 @@ loop.  Per program the VMEM working set is
     acc  (BQ, Dh) f32  output accumulator   (scratch, persists over kv)
     m, l (BQ, 128) f32 running max / sum    (scratch)
 
-Block shapes default to BQ = BK = 128 — MXU-aligned (the two matmuls are
-(BQ x Dh) @ (Dh x BK) and (BQ x BK) @ (BK x Dh); with Dh in {64, 128}
-every contraction dim is a multiple of the 128x128 MXU tile or exactly
-half of it, which Mosaic handles natively).
+Block shapes come from the call's shape (``default_blocks``).  A
+non-causal call takes, for T and for S alike, the largest multiple of 128
+up to ``BLOCK_MAX`` = 1024, and no longer than the sequence, that pads it
+no further than 128-blocks do (ops.py still cuts a block to a sequence
+shorter than 128).  At 4096 tokens that is 1024 x 1024, a grid of (B, H,
+4, 4): K/V are read from HBM once per 1024 query rows instead of once per
+128, and the accumulator is rescaled once per 1024 keys.  On a v5e one
+call at f32[1,16,4096,64] takes 1.79 ms at 1024 x 1024 against 8.91 ms at
+128 x 128 (the kernel alone 0.97 against 8.00 ms), and no pair is faster
+at B = 1, 2 or 4 (``benchmarks/flash_blocks.py``; the sweep is in
+PERF.md).  The (BQ, BK) f32 scores and probabilities, 4 MiB each, and the
+double-buffered q/k/v blocks fit the default scoped VMEM at every B (1024
+x 2048 does not, at B = 4).  Causal calls (LM prefill) keep 128 x 128:
+masking on the diagonal is per element, and a larger block does more of
+that masked work.  Every block is MXU-aligned: the two matmuls are (BQ x
+Dh) @ (Dh x BK) and (BQ x BK) @ (BK x Dh), and Dh in {64, 128} is a
+multiple of the 128x128 MXU tile or exactly half of it, which Mosaic
+handles natively.
 
 Causal masking: programs whose kv block lies entirely above the causal
 diagonal still run (Pallas TPU grids are dense) but skip the matmuls via
@@ -33,9 +47,26 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-DEFAULT_BQ = 128
-DEFAULT_BK = 128
+BLOCK_MAX = 1024
+CAUSAL_BLOCK = 128
 NEG_INF = -2.0 ** 30
+
+
+def _fit(n: int, cap: int) -> int:
+    """Largest multiple of 128, at most ``cap`` and at most ``n`` (or 128),
+    that pads ``n`` to the same length as 128 does: 128 times the largest
+    divisor of n's count of 128-blocks within those bounds."""
+    m = -(-n // 128)
+    top = max(1, min(cap, n) // 128)
+    return 128 * max(d for d in range(1, top + 1) if m % d == 0)
+
+
+def default_blocks(T: int, S: int, causal: bool) -> tuple:
+    """(bq, bk) for a call with T queries and S keys (before ops.py clamps
+    a block to a short sequence)."""
+    if causal:
+        return CAUSAL_BLOCK, CAUSAL_BLOCK
+    return _fit(T, BLOCK_MAX), _fit(S, BLOCK_MAX)
 
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
@@ -92,8 +123,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
 
 
 def flash_attention_kernel(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
-                           causal: bool, scale: float,
-                           bq: int = DEFAULT_BQ, bk: int = DEFAULT_BK,
+                           causal: bool, scale: float, bq: int, bk: int,
                            kv_valid: int = 0,
                            interpret: bool) -> jnp.ndarray:
     """q: (B, H, T, Dh); k/v: (B, KV, S, Dh); H = KV * G.  T % bq == 0,

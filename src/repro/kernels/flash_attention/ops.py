@@ -106,7 +106,8 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
 
     q: (B, T, H, Dh); k/v: (B, S, KV, Dh).  Returns (B, T, H, Dh).
     ``bq``/``bk`` default to the autotuned block sizes for this shape
-    bucket (kernel defaults when untuned).  Differentiable.
+    bucket, else to ``kernel.default_blocks`` of the shape.
+    Differentiable.
     """
     if interpret is None:
         interpret = not _on_tpu()
@@ -115,10 +116,11 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
     scale = float(Dh ** -0.5 if scale is None else scale)
 
     if bq is None or bk is None:
+        dq, dk = K.default_blocks(T, S, causal)
         tuned = autotune.block(
             "flash_attention",
             autotune.flash_bucket(B, T, S, H, KV, Dh, causal, q.dtype),
-            {"bq": K.DEFAULT_BQ, "bk": K.DEFAULT_BK})
+            {"bq": dq, "bk": dk})
         bq = tuned["bq"] if bq is None else bq
         bk = tuned["bk"] if bk is None else bk
 

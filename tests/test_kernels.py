@@ -5,6 +5,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.kernels import autotune
 from repro.kernels.decode_attention.ops import decode_attention
 from repro.kernels.decode_attention.ref import decode_attention_ref
 from repro.kernels.flash_attention.ops import flash_attention
@@ -42,6 +43,7 @@ def _check(out, ref, dtype):
     (1, 300, 300, 8, 8, 64),      # MHA, ragged T (padding path)
     (2, 128, 384, 4, 1, 32),      # MQA, cross lengths
     (1, 100, 260, 6, 2, 128),     # ragged both, Dh = 128
+    (1, 2048, 2048, 2, 2, 64),    # non-causal: 2 x 2 blocks of 1024
 ])
 def test_flash_attention(shape, causal, dtype):
     B, T, S, H, KV, Dh = shape
@@ -64,6 +66,46 @@ def test_flash_attention_matches_model_sdpa():
     np.testing.assert_allclose(
         np.asarray(flash_attention(q, k, v, causal=True)),
         np.asarray(sdpa(q, k, v, causal=True)), rtol=3e-5, atol=3e-5)
+
+
+def _flash_call(eqns):
+    """The pallas_call equation under ``eqns`` (through jit and vjp)."""
+    for e in eqns:
+        if e.primitive.name == "pallas_call":
+            return e
+        for val in e.params.values():
+            inner = getattr(val, "jaxpr", val)
+            if isinstance(inner, jax.extend.core.Jaxpr):
+                found = _flash_call(inner.eqns)
+                if found is not None:
+                    return found
+    return None
+
+
+@pytest.mark.parametrize("T,S,causal,blocks,padded", [
+    (4096, 4096, False, (1024, 1024), (4096, 4096)),   # ViTDet global
+    (300, 260, False, (128, 128), (384, 384)),         # ragged both
+    (1000, 260, False, (512, 128), (1024, 384)),
+    (100, 260, False, (104, 128), (104, 384)),
+    (4096, 4096, True, (128, 128), (4096, 4096)),      # prefill keeps 128
+    (20, 12, False, (24, 16), (24, 16)),               # tiny: _round8
+])
+def test_flash_default_blocks(T, S, causal, blocks, padded, monkeypatch):
+    """Untuned, the blocks come from the shape, and pad T and S to the
+    lengths 128-blocks give."""
+    monkeypatch.setattr(autotune, "_ENABLED", False)
+    q = jax.ShapeDtypeStruct((1, T, 2, 64), jnp.float32)
+    k = jax.ShapeDtypeStruct((1, S, 2, 64), jnp.float32)
+    jaxpr = jax.make_jaxpr(lambda q, k: flash_attention(
+        q, k, k, causal=causal, interpret=True))(q, k)
+    call = _flash_call(jaxpr.jaxpr.eqns)
+    q_map, k_map = call.params["grid_mapping"].block_mappings[:2]
+    got = tuple(m.block_shape[2].block_size for m in (q_map, k_map))
+    assert got == blocks
+    assert tuple(v.aval.shape[2] for v in call.invars[:2]) == padded
+    for n, p in zip((T, S), padded):
+        r8 = max(8, -(-n // 8) * 8)
+        assert p == -(-n // min(128, r8)) * min(128, r8)
 
 
 # ---------------------------------------------------------------------------

@@ -58,10 +58,15 @@ def _window(s):
         q, q, q, s((1,), jnp.int32))
 
 
-def _flash(s):
-    q = s((1, T, H, DH), jnp.float32)
+def _flash(s, b=1):
+    q = s((b, T, H, DH), jnp.float32)
     return (lambda q, k, v: flash_attention(q, k, v, interpret=False),
             q, q, q)
+
+
+def _flash_b4(s):
+    # the grid's largest B bucket: the default blocks fit VMEM there too
+    return _flash(s, 4)
 
 
 def _pool(s):
@@ -104,6 +109,7 @@ def _int8(s):
 CASES = {
     "window_flagged_bw64": _window,
     "flash_4096": _flash,
+    "flash_4096_b4": _flash_b4,
     "avg_pool_rgb_8x1024": _pool,
     "avg_pool_patches_d1024": _pool_patches,
     "nn_upsample_d1024": _upsample,
