@@ -28,8 +28,8 @@ sites.  Tests that monkeypatch the env var must call
   env var (cached) > per-call ``backend`` arg > ``set_backend()`` > auto
 
 Only the *shapes the kernels support* are routed to Pallas; anything
-else (query offsets, multi-token ``kv_len`` masks) stays on the XLA
-path — the dispatcher is a router, not a second implementation.
+else (query offsets, causal or cross-length ``kv_len`` masks) stays on
+the XLA path — the dispatcher is a router, not a second implementation.
 """
 from __future__ import annotations
 
@@ -207,12 +207,15 @@ def window_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
 
 
 def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
-                    causal: bool = False) -> jnp.ndarray:
+                    causal: bool = False,
+                    kv_len: Optional[jnp.ndarray] = None) -> jnp.ndarray:
     """Pallas flash attention (ViTDet global blocks / LM prefill).
 
-    q: (B, T, H, Dh); k/v: (B, S, KV, Dh).  Differentiable (custom VJP).
+    q: (B, T, H, Dh); k/v: (B, S, KV, Dh).  ``kv_len``: optional (B,)
+    valid lengths of a padded non-causal self-attention (T == S); query
+    rows past a row's length come out zero.  Differentiable (custom VJP).
     """
-    return _flash.flash_attention(q, k, v, causal=causal)
+    return _flash.flash_attention(q, k, v, causal=causal, kv_len=kv_len)
 
 
 def decode_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,

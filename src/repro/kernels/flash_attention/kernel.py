@@ -36,11 +36,21 @@ Causal masking: programs whose kv block lies entirely above the causal
 diagonal still run (Pallas TPU grids are dense) but skip the matmuls via
 ``pl.when`` — only the (rare) diagonal blocks pay for the iota mask.
 
+Per-row lengths (``kv_len``, the padded ViT's global blocks before the
+restoration point): the lengths arrive as a scalar-prefetch operand, and
+row b keeps its first kv_len[b] keys and query rows; the query rows past
+it are padding and come out zero.  A block wholly past the length does
+no work, and the index maps send it the row's last live block, which the
+pipeline already holds, so no pad block is read from HBM; only the block
+the length ends in pays for the iota mask.  Calls without ``kv_len``
+build the kernel as before, with no scalar prefetch.
+
 fp32 softmax throughout; inputs may be bf16/f32 (cast on load).
 """
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -71,7 +81,7 @@ def default_blocks(T: int, S: int, causal: bool) -> tuple:
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
                   scale: float, causal: bool, bq: int, bk: int,
-                  n_kv_blocks: int, kv_valid: int):
+                  n_kv_blocks: int, kv_valid: int, kv_len=None):
     qi = pl.program_id(2)
     ki = pl.program_id(3)
 
@@ -85,7 +95,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
     q_start = qi * bq
     k_start = ki * bk
 
-    def _body():
+    def _body(limit=None):
+        """One kv block; keys at or past ``limit`` (traced) are masked."""
         q = q_ref[...].astype(jnp.float32)            # (BQ, Dh)
         k = k_ref[...].astype(jnp.float32)            # (BK, Dh)
         v = v_ref[...].astype(jnp.float32)
@@ -98,6 +109,9 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
             if causal and kv_valid % bk:
                 mask = mask & (k_pos < kv_valid)
             s = jnp.where(mask, s, NEG_INF)
+        if limit is not None:
+            k_pos = k_start + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
+            s = jnp.where(k_pos < limit, s, NEG_INF)
 
         m_prev = m_ref[:, 0]                          # (BQ,)
         m_cur = jnp.maximum(m_prev, jnp.max(s, axis=1))
@@ -109,7 +123,11 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
             p, v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    if causal:   # skip kv blocks entirely above the causal diagonal
+    if kv_len is not None:   # skip blocks past the row's length
+        live = (q_start < kv_len) & (k_start < kv_len)
+        pl.when(live & (k_start + bk <= kv_len))(_body)
+        pl.when(live & (k_start + bk > kv_len))(lambda: _body(kv_len))
+    elif causal:   # skip kv blocks entirely above the causal diagonal
         pl.when(k_start <= q_start + bq - 1)(_body)
     else:
         _body()
@@ -117,48 +135,88 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
     @pl.when(ki == n_kv_blocks - 1)
     def _finalize():
         l = l_ref[:, 0]
-        # fully-masked rows (causal padding) have l == 0 -> emit zeros
+        # fully-masked rows (causal padding, skipped blocks) have l == 0
+        # -> emit zeros
         inv = jnp.where(l > 0.0, 1.0 / jnp.maximum(l, 1e-30), 0.0)
-        o_ref[...] = (acc_ref[...] * inv[:, None]).astype(o_ref.dtype)
+        out = acc_ref[...] * inv[:, None]
+        if kv_len is not None:   # query rows past the length: zeros
+            rows = q_start + jax.lax.broadcasted_iota(jnp.int32, out.shape, 0)
+            out = jnp.where(rows < kv_len, out, 0.0)
+        o_ref[...] = out.astype(o_ref.dtype)
 
 
-def flash_attention_kernel(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
+def flash_attention_kernel(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
+                           kv_len: Optional[jnp.ndarray] = None, *,
                            causal: bool, scale: float, bq: int, bk: int,
                            kv_valid: int = 0,
                            interpret: bool) -> jnp.ndarray:
     """q: (B, H, T, Dh); k/v: (B, KV, S, Dh); H = KV * G.  T % bq == 0,
     S % bk == 0 (ops.py pads).  ``kv_valid``: number of real (unpadded)
-    keys; 0 means all S.  Returns (B, H, T, Dh) in q.dtype."""
+    keys; 0 means all S.  ``kv_len``: optional (B,) int32 per-row
+    lengths of a non-causal self-attention (T == S, each at most the
+    unpadded length): row b attends to its first kv_len[b] keys, and its
+    query rows from kv_len[b] on come out zero.  Returns (B, H, T, Dh) in
+    q.dtype."""
     B, H, T, Dh = q.shape
     KV, S = k.shape[1], k.shape[2]
     group = H // KV
     n_q = T // bq
     n_k = S // bk
-    kv_valid = kv_valid or S
+    kv_valid = S if kv_len is not None else (kv_valid or S)
 
     grid = (B, H, n_q, n_k)
+    if kv_len is None:
+        def q_map(b, h, q_, k_):
+            return (b, h, q_, 0)
+
+        def kv_map(b, h, q_, k_):
+            return (b, h // group, k_, 0)
+
+        o_map = q_map
+    else:
+        def last(n, blk):    # the last block holding a row < n (0 if none)
+            return jnp.maximum(n - 1, 0) // blk
+
+        def q_map(b, h, q_, k_, lens):
+            return (b, h, jnp.minimum(q_, last(lens[b], bq)), 0)
+
+        def kv_map(b, h, q_, k_, lens):
+            kl = last(lens[b], bk)
+            live_q = q_ <= last(lens[b], bq)
+            return (b, h // group,
+                    jnp.where(live_q, jnp.minimum(k_, kl), kl), 0)
+
+        def o_map(b, h, q_, k_, lens):
+            return (b, h, q_, 0)
+
     kernel = functools.partial(
         _flash_kernel, scale=scale, causal=causal, bq=bq, bk=bk,
         n_kv_blocks=n_k, kv_valid=kv_valid)
-
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
+    specs = dict(
         in_specs=[
-            pl.BlockSpec((None, None, bq, Dh),
-                         lambda b, h, q_, k_: (b, h, q_, 0)),
-            pl.BlockSpec((None, None, bk, Dh),
-                         lambda b, h, q_, k_: (b, h // group, k_, 0)),
-            pl.BlockSpec((None, None, bk, Dh),
-                         lambda b, h, q_, k_: (b, h // group, k_, 0)),
+            pl.BlockSpec((None, None, bq, Dh), q_map),
+            pl.BlockSpec((None, None, bk, Dh), kv_map),
+            pl.BlockSpec((None, None, bk, Dh), kv_map),
         ],
-        out_specs=pl.BlockSpec((None, None, bq, Dh),
-                               lambda b, h, q_, k_: (b, h, q_, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, H, T, Dh), q.dtype),
+        out_specs=pl.BlockSpec((None, None, bq, Dh), o_map),
         scratch_shapes=[
             pltpu.VMEM((bq, Dh), jnp.float32),
             pltpu.VMEM((bq, 128), jnp.float32),
             pltpu.VMEM((bq, 128), jnp.float32),
         ],
+    )
+    out_shape = jax.ShapeDtypeStruct((B, H, T, Dh), q.dtype)
+    if kv_len is None:
+        return pl.pallas_call(kernel, grid=grid, out_shape=out_shape,
+                              interpret=interpret, **specs)(q, k, v)
+
+    def masked(lens_ref, *refs):
+        kernel(*refs, kv_len=lens_ref[pl.program_id(0)])
+
+    return pl.pallas_call(
+        masked,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=grid, **specs),
+        out_shape=out_shape,
         interpret=interpret,
-    )(q, k, v)
+    )(kv_len, q, k, v)

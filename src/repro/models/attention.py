@@ -62,27 +62,33 @@ def sdpa(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
     """q: (B,T,H,Dh)  k/v: (B,S,KV,Dh) with H = KV * G.  Returns (B,T,H,Dh).
 
     ``q_offset``: absolute position of q[0] (decode: pos; prefill: 0).
-    ``kv_len``: optional per-batch valid cache length (B,) for decode.
+    ``kv_len``: optional per-batch valid length (B,): the cache length
+    for decode, the real tokens of a padded sequence otherwise.
     ``backend``: kernel backend (kernels.dispatch).  The Pallas lane
-    routes two shapes: the plain full-sequence case to the flash kernel,
-    and the one-token ``kv_len`` cache read (T == 1) to the decode
-    kernel (kernels/decode_attention).  Everything else — nonzero
-    ``q_offset``, multi-token ``kv_len`` masks (the padded ViT's
-    pre-restoration global blocks), explicit ``scale`` — stays on the
-    XLA path.
+    routes three shapes: the plain full-sequence case and the padded
+    non-causal self-attention with a ``kv_len`` (the padded ViT's
+    pre-restoration global blocks) to the flash kernel, and the
+    one-token ``kv_len`` cache read (T == 1) to the decode kernel
+    (kernels/decode_attention).  The flash kernel zeroes the query rows
+    from a row's ``kv_len`` on, where XLA computes them: they are pad
+    tokens, which the ViT never reads.  Everything else — nonzero ``q_offset``, explicit
+    ``scale``, ``kv_len`` with causal or cross-length attention — stays
+    on the XLA path.
 
     Long sequences (T > 2*Q_CHUNK) are processed as a lax.scan over query
     blocks so the live logits buffer is (B, C, H, S) instead of the full
     (B, T, H, S) — the XLA analogue of flash attention's tiling (the
     Pallas kernel in kernels/flash_attention does the same on-chip).
     """
-    plain = (kv_len is None and isinstance(q_offset, int) and q_offset == 0
-             and scale is None)
-    if plain and dispatch.use_pallas(backend):
-        return dispatch.flash_attention(q, k, v, causal=causal)
+    aligned = isinstance(q_offset, int) and q_offset == 0 and scale is None
+    padded = (kv_len is not None and not causal
+              and 1 < q.shape[1] == k.shape[1])
+    if (aligned and (kv_len is None or padded)
+            and dispatch.use_pallas(backend)):
+        return dispatch.flash_attention(q, k, v, causal=causal,
+                                        kv_len=kv_len)
     decode = (kv_len is not None and q.shape[1] == 1 and not causal
-              and isinstance(q_offset, int) and q_offset == 0
-              and scale is None)
+              and aligned)
     if decode and dispatch.use_pallas(backend):
         return dispatch.decode_attention(q, k, v, kv_len)
     T = q.shape[1]
@@ -243,8 +249,9 @@ def attention_forward(cfg: ModelConfig, p, x, positions, *,
 
     Length-bucketed padded sequences thread their traced validity here:
     ``kv_len`` (B,) masks pad KEYS out of global attention (the sdpa
-    masked path — never routed to the Pallas flash kernel), ``win_valid``
-    (B,) flags whole pad windows for window attention.
+    masked path: the Pallas flash kernel's per-row lengths, or XLA's
+    masked softmax), ``win_valid`` (B,) flags whole pad windows for
+    window attention.
     """
     q, k, v = _project_qkv(cfg, p, x, positions, rope)
     if window > 0:

@@ -608,10 +608,16 @@ class ServerModel:
             assert lb >= max(nws) and lb in self.length_edges, \
                 f"lb_override {lb} cannot hold {max(nws)} windows " \
                 f"(edges {self.length_edges})"
-            with spans.span("serve.layout"):
+            with spans.span("serve.layout") as lay:
                 layouts = [pt.plan_layout(p.states, lb, self.part)
                            for p in plans]
                 arrays, wave_key = pt.stack_plan_layouts(layouts)
+                # key slots of the real rows' pre-restoration global
+                # attention, and the pad keys among them (the flash
+                # kernel skips their blocks)
+                w2 = self.part.window ** 2
+                lay.add("keys", lb * w2 * B)
+                lay.add("pad_keys", sum(lb - n for n in nws) * w2)
             tiles_in = self._wave_tiles(layouts, caches, npad)
             # mixed execs always capture at their restoration point;
             # beta_eff == 0 has none, so it never captures

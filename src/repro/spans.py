@@ -24,6 +24,9 @@ Counts on a span (:meth:`Span.add`) are the host<->device calls made
 inside it: ``launches`` (the wave's executable), ``jit_launches`` (every
 other device computation: jitted index ops, eager ops, row slices),
 ``h2d`` (host->device transfers) and ``d2h`` (device->host reads).
+``serve.layout`` also counts work: ``keys``, the key slots of the real
+rows' pre-restoration global attention (length bucket x window tokens
+per row), and ``pad_keys``, the padding among them.
 
 Device work is named by ``jax.named_scope`` inside the traced functions
 (:data:`SCOPES`).  A TPU trace names each op by its HLO instruction

@@ -217,20 +217,39 @@ def test_sdpa_decode_routes_to_decode_kernel():
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), **TOL)
 
 
-def test_sdpa_unsupported_shapes_stay_on_xla():
-    """Shapes the decode kernel does NOT support — multi-token queries
-    with kv_len (padded ViT global blocks) and nonzero q_offset — must
-    fall back to XLA, not mis-route (parity pins the routing)."""
+def test_sdpa_unsupported_shapes_stay_on_xla(monkeypatch):
+    """Multi-token queries with a (B,) kv_len over their own keys (the
+    padded ViT's pre-restoration global blocks) route to the flash
+    kernel's per-row lengths, in parity with XLA on every valid query
+    row (the kernel zeroes the rows past a length).  Shapes no kernel
+    supports — nonzero q_offset, kv_len over keys of another length —
+    fall back to XLA, not mis-route."""
+    calls = []
+    real = dispatch.flash_attention
+
+    def spy(*a, **kw):
+        calls.append(kw.get("kv_len") is not None)
+        return real(*a, **kw)
+
+    monkeypatch.setattr(dispatch, "flash_attention", spy)
     ks = jax.random.split(jax.random.PRNGKey(7), 3)
     k = jax.random.normal(ks[1], (2, 32, 4, 16))
     v = jax.random.normal(ks[2], (2, 32, 4, 16))
     kv_len = jnp.array([7, 32])
-    # multi-token query + kv_len mask
-    qm = jax.random.normal(ks[0], (2, 8, 4, 16))
-    np.testing.assert_allclose(
-        np.asarray(attn.sdpa(qm, k, v, kv_len=kv_len, backend="pallas")),
-        np.asarray(attn.sdpa(qm, k, v, kv_len=kv_len, backend="xla")),
-        **TOL)
+    # multi-token self-attention + kv_len mask: the flash route
+    qm = jax.random.normal(ks[0], (2, 32, 4, 16))
+    out = np.asarray(attn.sdpa(qm, k, v, kv_len=kv_len, backend="pallas"))
+    assert calls == [True]
+    ref = np.asarray(attn.sdpa(qm, k, v, kv_len=kv_len, backend="xla"))
+    assert calls == [True]
+    for b, n in enumerate([7, 32]):
+        np.testing.assert_allclose(out[b, :n], ref[b, :n], **TOL)
+        assert not out[b, n:].any()
+    # multi-token queries over keys of another length stay on XLA
+    qx = qm[:, :8]
+    np.testing.assert_array_equal(
+        np.asarray(attn.sdpa(qx, k, v, kv_len=kv_len, backend="pallas")),
+        np.asarray(attn.sdpa(qx, k, v, kv_len=kv_len, backend="xla")))
     # one-token causal decode with an explicit query offset
     q1 = jax.random.normal(ks[0], (2, 1, 4, 16))
     np.testing.assert_allclose(
@@ -238,6 +257,7 @@ def test_sdpa_unsupported_shapes_stay_on_xla():
                              q_offset=16, backend="pallas")),
         np.asarray(attn.sdpa(q1, k, v, kv_len=kv_len, causal=True,
                              q_offset=16, backend="xla")), **TOL)
+    assert calls == [True]
 
 
 def test_window_sdpa_backend_parity():

@@ -108,6 +108,91 @@ def test_flash_default_blocks(T, S, causal, blocks, padded, monkeypatch):
         assert p == -(-n // min(128, r8)) * min(128, r8)
 
 
+@pytest.mark.parametrize("T,kv_len,blocks", [
+    (4096, False, (1024, 1024)),    # post-restoration global blocks
+    (1536, True, (768, 768)),       # padded pre-restoration, bucket 24
+    (3072, True, (1024, 1024)),     # bucket 48
+])
+def test_flash_scalar_prefetch_only_with_kv_len(T, kv_len, blocks,
+                                                monkeypatch):
+    """A call without ``kv_len`` builds the pallas_call with no
+    scalar-prefetch operand; a call with one passes the lengths as the
+    only one, at the padded shape's default blocks."""
+    monkeypatch.setattr(autotune, "_ENABLED", False)
+    q = jax.ShapeDtypeStruct((2, T, 2, 64), jnp.float32)
+    n = jax.ShapeDtypeStruct((2,), jnp.int32)
+    jaxpr = jax.make_jaxpr(lambda q, n: flash_attention(
+        q, q, q, kv_len=n if kv_len else None, interpret=True))(q, n)
+    call = _flash_call(jaxpr.jaxpr.eqns)
+    gm = call.params["grid_mapping"]
+    assert gm.num_index_operands == int(kv_len)
+    assert len(call.invars) == 3 + int(kv_len)
+    if kv_len:
+        assert call.invars[0].aval.shape == (2,)
+        assert call.invars[0].aval.dtype == jnp.int32
+    got = tuple(m.block_shape[2].block_size
+                for m in gm.block_mappings[:2])
+    assert got == blocks
+
+
+def _masked_case(lens, H=4, KV=2, T=512, Dh=32):
+    ks = jax.random.split(jax.random.PRNGKey(sum(lens)), 3)
+    q = _rand(ks[0], (len(lens), T, H, Dh), jnp.float32)
+    k = _rand(ks[1], (len(lens), T, KV, Dh), jnp.float32)
+    v = _rand(ks[2], (len(lens), T, KV, Dh), jnp.float32)
+    return q, k, v, jnp.asarray(lens, jnp.int32)
+
+
+@pytest.mark.parametrize("lens", [
+    (1, 512),      # one key; the full length
+    (200, 256),    # a partial block; an exact block boundary
+    (100, 384),    # three tail blocks skipped; one
+])
+def test_flash_kv_len_matches_masked_sdpa(lens):
+    """Per-row lengths (forced 128-blocks, 4 x 4 of them): every valid
+    query row matches XLA's masked softmax, and the rows past a length
+    come out zero."""
+    from repro.models.attention import _sdpa_dense
+    q, k, v, kv_len = _masked_case(lens)
+    out = flash_attention(q, k, v, kv_len=kv_len, bq=128, bk=128)
+    ref = _sdpa_dense(q, k, v, kv_len=kv_len)
+    for b, n in enumerate(lens):
+        _check(out[b, :n], ref[b, :n], jnp.float32)
+        assert not np.asarray(out[b, n:]).any()
+
+
+def test_flash_kv_len_zero_emits_zeros():
+    q, k, v, kv_len = _masked_case((0, 300))
+    out = np.asarray(flash_attention(q, k, v, kv_len=kv_len, bq=128,
+                                     bk=128))
+    assert not out[0].any() and not out[1, 300:].any()
+    assert np.abs(out[1, :300]).min(axis=-1).max() > 0
+
+
+def test_flash_kv_len_grad_matches_xla():
+    """jax.grad through the kernel's custom VJP with per-row lengths
+    equals the gradient of XLA's masked sdpa, over a loss on the valid
+    query rows."""
+    from repro.models.attention import sdpa
+    q, k, v, kv_len = _masked_case((200, 384))
+    valid = (jnp.arange(q.shape[1])[None, :]
+             < kv_len[:, None])[:, :, None, None]
+
+    def grads(f):
+        def loss(q, k, v):
+            o = f(q, k, v)
+            return jnp.sum(jnp.where(valid, o * jnp.cos(o), 0.0))
+        return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+    got = grads(lambda q, k, v: flash_attention(q, k, v, kv_len=kv_len,
+                                                bq=128, bk=128))
+    ref = grads(lambda q, k, v: sdpa(q, k, v, kv_len=kv_len,
+                                     backend="xla"))
+    for g, r in zip(got, ref):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(r),
+                                   rtol=1e-4, atol=1e-4)
+
+
 # ---------------------------------------------------------------------------
 # window attention
 

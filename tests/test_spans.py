@@ -9,6 +9,7 @@ import pytest
 
 from repro import spans
 from repro.configs.vitdet_l import SIM
+from repro.core import partition as pt
 from repro.core import vit_backbone as vb
 from repro.core.partition import LOW, REUSE, RegionPlan
 from repro.models import registry
@@ -185,6 +186,9 @@ def test_deferred_reuse_wave_span_tree_and_device_calls(setup, tmp_path):
     # the block) and a read
     assert counts == {
         ("serve.stage", "h2d"): 1,
+        # 36 and 52 windows of 4 tokens at length bucket 64
+        ("serve.layout", "keys"): 2 * 64 * 4,
+        ("serve.layout", "pad_keys"): (28 + 12) * 4,
         ("serve.tiles", "h2d"): 1, ("serve.tiles", "jit_launches"): 6,
         ("serve.args", "h2d"): 8,
         ("serve.launch", "launches"): 1,
@@ -195,6 +199,29 @@ def test_deferred_reuse_wave_span_tree_and_device_calls(setup, tmp_path):
     launched = sum(n for (_, k), n in counts.items()
                    if k in ("launches", "jit_launches"))
     assert _trace_executions(tmp_path) == launched == 23
+
+
+def test_layout_counts_keys_and_pad_keys_of_real_rows(setup):
+    """``serve.layout`` counts the key slots of the wave's real rows at
+    its length bucket and the pad keys among them, from the plans'
+    window counts; a row that only pads the B bucket counts nothing."""
+    params, part = setup
+    server = ServerModel(SIM, params, top_k=8, score_thresh=0.0,
+                         b_buckets=(2,))
+    w2 = part.window ** 2
+    waves = [([_plan(part, low=range(12)), _plan(part, low=range(16))],
+              48, [28, 16]),
+             ([_plan(part, low=range(16))], 24, [16])]
+    for plans, lb, nws in waves:
+        assert [pt.plan_n_windows(p, part) for p in plans] == nws
+        spans.enable()
+        server.infer_wave(_frames(len(plans)), plans, beta=2)
+        spans.disable()
+        (lay,) = [s for s in spans.recorded() if s.name == "serve.layout"]
+        spans.clear()
+        assert lay.counts == {"keys": len(plans) * lb * w2,
+                              "pad_keys": sum(lb - n for n in nws) * w2}
+        assert lay.calls == 0
 
 
 def test_named_scopes_leave_outputs_bit_identical(setup, monkeypatch):

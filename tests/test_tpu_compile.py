@@ -69,6 +69,22 @@ def _flash_b4(s):
     return _flash(s, 4)
 
 
+def _flash_masked(s, b, t):
+    # the padded pre-restoration global block, per-row lengths on
+    q = s((b, t, H, DH), jnp.float32)
+    return (lambda q, k, v, n: flash_attention(q, k, v, kv_len=n,
+                                               interpret=False),
+            q, q, q, s((b,), jnp.int32))
+
+
+def _flash_masked_1536(s):
+    return _flash_masked(s, 1, 24 * W2)
+
+
+def _flash_masked_3072_b4(s):
+    return _flash_masked(s, 4, 48 * W2)
+
+
 def _pool(s):
     return (lambda x: avg_pool_2d(x, 2, interpret=False),
             s((8, 1024, 1024, 3), jnp.float32))
@@ -110,6 +126,8 @@ CASES = {
     "window_flagged_bw64": _window,
     "flash_4096": _flash,
     "flash_4096_b4": _flash_b4,
+    "flash_masked_1536": _flash_masked_1536,
+    "flash_masked_3072_b4": _flash_masked_3072_b4,
     "avg_pool_rgb_8x1024": _pool,
     "avg_pool_patches_d1024": _pool_patches,
     "nn_upsample_d1024": _upsample,
